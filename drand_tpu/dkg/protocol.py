@@ -163,17 +163,6 @@ class DKGProtocol:
                 issuer=lambda b: b.dealer_index,
                 note=lambda b: FLIGHT.dkg.note_bundle(
                     sid, "deal", b.dealer_index, now=self.c.clock.now()))
-            # deliberately ON-LOOP (loopblock baseline entry): deal
-            # admission is batched host/device crypto, but the DKG runs
-            # in a dedicated phase-clock-driven setup window — an
-            # executor hand-off here suspends the node between a phase
-            # deadline and its response push, and a concurrently
-            # advancing clock (FakeClock tests; aggressive operator
-            # timeouts) can close the response window while the thread
-            # runs. Bounded: a few batched dispatches per DKG, not per
-            # round — and sliced into _ADMIT_CHUNK-dealer chunks with
-            # cooperative yields so a n=1024 admission cannot starve
-            # the phase clock either.
             await self._process_deals(deals)
 
             FLIGHT.dkg.note_phase(sid, "response", now=self.c.clock.now())
@@ -204,7 +193,7 @@ class DKGProtocol:
                     note=lambda b: FLIGHT.dkg.note_bundle(
                         sid, "justification", b.dealer_index,
                         now=self.c.clock.now()))
-                self._process_justifications(justs)
+                await self._process_justifications(justs)
 
             FLIGHT.dkg.note_phase(sid, "finish", now=self.c.clock.now())
             result = self._finish(dealers)
@@ -236,38 +225,21 @@ class DKGProtocol:
         ECIES encrypts — two 255-bit point muls each, the dominant
         dealing cost at n=1024 — handed to ``asyncio.to_thread`` in
         bounded chunks so the dealer never parks the event loop behind
-        ~30 s of sequential encryption. Dealing runs BEFORE the dealer
-        enters its deal-phase collect, so the thread hand-off here has
-        no phase-deadline interplay (unlike admission, which stays
-        on-loop — see _process_deals)."""
+        ~30 s of sequential encryption."""
         nodes = self.c.new_nodes
-        commit_pts, shares = await self._offload(
+        commit_pts, shares = await asyncio.to_thread(
             lambda: (poly.commit().commits,
                      poly.eval_many([n.index for n in nodes])))
         commits = tuple(c.to_bytes() for c in commit_pts)
         deals: list[Deal] = []
         for s0 in range(0, len(nodes), _DEAL_ENC_CHUNK):
-            deals.extend(await self._offload(
+            deals.extend(await asyncio.to_thread(
                 self._encrypt_deals, nodes[s0:s0 + _DEAL_ENC_CHUNK],
                 shares[s0:s0 + _DEAL_ENC_CHUNK]))
         bundle = DealBundle(
             dealer_index=self._dealer_index, commits=commits,
             deals=tuple(deals), session_id=self.c.nonce)
         return _signed(bundle, self.c.longterm)
-
-    async def _offload(self, fn, *args):
-        """Dealing work goes to a worker thread ONLY on the wall clock.
-        A FakeClock test driver advances time whenever the loop is idle
-        — a dealer parked in ``asyncio.to_thread`` registers no clock
-        waiter, so the driver would burn every phase window in real
-        milliseconds while the thread still deals (the crashed-dealer
-        FakeClock test deadlocks exactly so). Deterministic clocks keep
-        dealing inline, with a cooperative yield per chunk instead."""
-        if isinstance(self.c.clock, SystemClock):
-            return await asyncio.to_thread(fn, *args)
-        res = fn(*args)
-        await asyncio.sleep(0)
-        return res
 
     @staticmethod
     def _encrypt_deals(nodes: list[Node], shares) -> list[Deal]:
@@ -289,14 +261,14 @@ class DKGProtocol:
           at our index, one dispatch) + ``batch.share_checks`` (every
           g·s through one fixed-base-comb pass).
 
-        The work stays ON the event loop (the loopblock baseline entry
-        documents why an executor hand-off is worse here) but is sliced
-        into _ADMIT_CHUNK-dealer chunks with a cooperative yield
-        between slices, so a n=1024 admission cannot starve the phase
-        clock (tests/test_zz_dkg_scale.py proves the response window
-        still closes under FakeClock). Rejections are attributable:
-        each mints dkg_bundle_rejects_total{phase,verdict} and a
-        flight-recorder note instead of a silent drop."""
+        The engine dispatches (``reshare_bindings``, ``eval_commits``)
+        run on a worker thread; the rest is sliced into
+        _ADMIT_CHUNK-dealer chunks with a cooperative yield between
+        slices, so a n=1024 admission cannot starve the phase clock
+        (tests/test_zz_dkg_scale.py proves the response window still
+        closes under FakeClock). Rejections are attributable: each mints
+        dkg_bundle_rejects_total{phase,verdict} and a flight-recorder
+        note instead of a silent drop."""
         from .. import metrics
 
         pending = []
@@ -329,8 +301,8 @@ class DKGProtocol:
             # dealer constant terms must be their OLD public shares —
             # the key-preservation binding of a reshare, decided for
             # the whole phase in one batched dispatch
-            verdicts = batch.reshare_bindings(
-                self._old_pub,
+            verdicts = await asyncio.to_thread(
+                batch.reshare_bindings, self._old_pub,
                 [(b.dealer_index, pub.commit()) for b, pub in admitted])
             kept = []
             for (b, pub), ok in zip(admitted, verdicts):
@@ -348,8 +320,9 @@ class DKGProtocol:
         if self._share_index is None or not admitted:
             return
 
-        evals = batch.eval_commits([pub for _, pub in admitted],
-                                   self._share_index)
+        evals = await asyncio.to_thread(
+            batch.eval_commits, [pub for _, pub in admitted],
+            self._share_index)
         checks: list[tuple[int, int, PointG1]] = []
         for s0 in range(0, len(admitted), _ADMIT_CHUNK):
             for (b, _), ev in zip(admitted[s0:s0 + _ADMIT_CHUNK],
@@ -435,7 +408,7 @@ class DKGProtocol:
             session_id=self.c.nonce)
         return _signed(bundle, self.c.longterm)
 
-    def _process_justifications(self, bundles) -> None:
+    async def _process_justifications(self, bundles) -> None:
         """A phase's justification bundles verified in batch: each
         complained dealer's admitted commitment polynomial evaluated at
         ALL its disputed share indices in one dispatch
@@ -449,7 +422,7 @@ class DKGProtocol:
         failing one leaves it open and mints an attributable reject."""
         from .. import metrics
 
-        work: list[tuple[int, int, int, PointG1]] = []
+        todo = []
         for b in bundles:
             pub = self._valid_commits.get(b.dealer_index)
             opened = self._complaints_open.get(b.dealer_index, set())
@@ -457,15 +430,18 @@ class DKGProtocol:
                 continue
             wanted = [j for j in b.justifications
                       if j.share_index in opened]
-            if not wanted:
-                continue
-            evs = batch.eval_poly_indices(
-                pub, [j.share_index for j in wanted])
-            for j, ev in zip(wanted, evs):
-                work.append((b.dealer_index, j.share_index,
-                             j.share % R, ev))
-        if not work:
+            if wanted:
+                todo.append((b.dealer_index, pub, wanted))
+        if not todo:
             return
+        # the engine dispatches run on a worker thread, like admission's
+        evals = await asyncio.to_thread(lambda: [
+            batch.eval_poly_indices(pub, [j.share_index for j in wanted])
+            for _, pub, wanted in todo])
+        work: list[tuple[int, int, int, PointG1]] = [
+            (dealer, j.share_index, j.share % R, ev)
+            for (dealer, _, wanted), evs in zip(todo, evals)
+            for j, ev in zip(wanted, evs)]
         oks = batch.share_checks([(val, ev) for _, _, val, ev in work])
         for (dealer, idx, val, _), ok in zip(work, oks):
             if ok:

@@ -20,7 +20,9 @@ often forgot).
 Prometheus counters/gauges are NOT rewound — prometheus state is
 cumulative by design and every metric assertion in the tree reads
 deltas (conftest.sample_count) — only the recorder/ring state that
-snapshot-style assertions read directly.
+snapshot-style assertions read directly, and the per-peer breaker-state
+gauge, which the incident time-series sample reads as current state
+(a stale OPEN child would read as an open breaker in the next scenario).
 
 Imports are lazy per the ``drand_tpu.obs`` cheapness rule: pulling this
 module in costs nothing until a reset actually runs.
@@ -35,8 +37,10 @@ def reset_observability() -> None:
     """Reset FLIGHT (rounds, peers, reachability, DKG timelines),
     HEALTH, TRACER, INCIDENTS (time-series ring + incident state) and
     the remediation ENGINE (ledger, budget, cooldowns, active markers)
-    to boot state. Safe against concurrent note_* calls — each
-    singleton's own reset carries its lock discipline."""
+    and the breaker-state gauge to boot state. Safe against concurrent
+    note_* calls — each singleton's own reset carries its lock
+    discipline."""
+    from .. import metrics
     from .flight import FLIGHT
     from .health import HEALTH
     from .incident import INCIDENTS
@@ -48,6 +52,7 @@ def reset_observability() -> None:
     TRACER.reset()
     INCIDENTS.reset()
     ENGINE.reset()
+    metrics.PEER_BREAKER_STATE.clear()
 
 
 @contextmanager

@@ -487,7 +487,6 @@ class ChaosBeaconNetwork:
 
     async def advance_to_genesis(self) -> None:
         await self.base_clock.advance_to(self.genesis_time)
-        await self._quiesce()
 
     def stop_all(self) -> None:
         for h in self.handlers:
@@ -576,58 +575,21 @@ class ChaosBeaconNetwork:
         probe = max(range(len(self.stores)), key=self._head)
         return self.stores[probe].last()
 
-    async def _quiesce(self, stable_checks: int = 3,
-                       interval: float = 0.005,
-                       timeout: float = 3.0) -> None:
-        """Let the event loop + to_thread workers drain while the fake
-        clock PARKS: wait until per-node heads and the spawned-task
-        count are stable for a few consecutive real-time checks."""
-        from ..utils import aio
-
-        loop = asyncio.get_running_loop()
-        deadline = loop.time() + timeout
-        last, stable = None, 0
-        while loop.time() < deadline:
-            await asyncio.sleep(interval)
-            sig = (tuple(self._head(i) for i in range(len(self.stores))),
-                   aio.pending_tasks())
-            if sig == last:
-                stable += 1
-                if stable >= stable_checks:
-                    return
-            else:
-                last, stable = sig, 0
-
-    # intra-round wake targets closer together than this (fake seconds)
-    # are stepped through in ONE hop before quiescing: a jittered 32-node
-    # round scatters ~1000 distinct delivery times, and a real-time
-    # quiesce at every single one would cost minutes of wall clock for
-    # microseconds of work. Timestamps stay exact per delivery (advance
-    # steps through each target); only the quiesce points coalesce, so
-    # quorum times can read at most this much late.
-    coalesce_s = 0.25
-
     async def advance_round(self) -> int:
         """Advance INTO the next round and step through the intra-round
-        wake targets (delayed links, skewed tickers, catchup breathers),
-        quiescing at each coalesced stop so deliveries timestamp at
-        their own wake times. Returns the advanced-into round."""
+        wake targets (delayed links, skewed tickers, catchup breathers)
+        one by one; the clock quiesces at each, so deliveries timestamp
+        at their own wake times. Returns the advanced-into round."""
         period = self.group.period
         now = self.base_clock.now()
         nxt, ttime = time_math.next_round(int(now), period,
                                           self.genesis_time)
         await self.base_clock.advance(ttime - now)
-        await self._quiesce()
         end = ttime + period
-        while True:
+        nw = self.base_clock.next_wake()
+        while nw is not None and nw < end - 1e-9:
+            await self.base_clock.advance(nw - self.base_clock.now())
             nw = self.base_clock.next_wake()
-            if nw is None or nw >= end - 1e-9:
-                break
-            stop = min(nw + self.coalesce_s, end - 1e-9)
-            while nw is not None and nw <= stop:
-                await self.base_clock.advance(nw - self.base_clock.now())
-                nw = self.base_clock.next_wake()
-            await self._quiesce()
         return nxt
 
     # ------------------------------------------------------ observation
@@ -748,7 +710,6 @@ class ChaosBeaconNetwork:
             # the beacon rounds keep ticking underneath: churn
             for _ in range(8):
                 await self.base_clock.advance(phase_timeout)
-                await self._quiesce(stable_checks=2, timeout=1.0)
 
         runs = asyncio.gather(*(DKGProtocol(configs[i], boards[i]).run()
                                 for i in live))

@@ -10,8 +10,7 @@ Reproduces the reference's test machinery (SURVEY.md §4):
 
 from __future__ import annotations
 
-import asyncio
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 from ..chain.engine.handler import BeaconConfig, Handler
 from ..chain.store import MemStore, Store
@@ -21,6 +20,7 @@ from ..key.keys import DistPublic, Node, Pair, Share, new_key_pair
 from ..net.transport import LocalNetwork
 from ..utils.clock import Clock, FakeClock
 from ..utils.logging import default_logger
+from .quiesce import wait_for
 
 
 def synthesize_shares(n: int, t: int, seed: bytes = b"test-dkg") -> tuple[list[Share], DistPublic]:
@@ -59,6 +59,13 @@ def make_test_group(
     )
     group.get_genesis_seed()
     return group, pairs, shares
+
+
+def _head(store: Store) -> int:
+    try:
+        return store.last().round
+    except Exception:  # noqa: BLE001 — empty store during boot
+        return 0
 
 
 @dataclass
@@ -127,20 +134,11 @@ class BeaconTestNetwork:
 
     async def wait_round(self, node_idx: int, round_no: int, timeout: float = 30.0) -> None:
         """Wait (real time) until the node's chain reaches round_no."""
-        node = self.nodes[node_idx]
-        deadline = asyncio.get_event_loop().time() + timeout
-        while True:
-            try:
-                if node.store.last().round >= round_no:
-                    return
-            except Exception:
-                pass
-            if asyncio.get_event_loop().time() > deadline:
-                raise TimeoutError(
-                    f"node {node_idx} never reached round {round_no} "
-                    f"(at {node.store.last().round})"
-                )
-            await asyncio.sleep(0.01)
+        store = self.nodes[node_idx].store
+        await wait_for(
+            lambda: _head(store) >= round_no,
+            lambda: f"node {node_idx} never reached round {round_no} "
+                    f"(at {_head(store)})", timeout)
 
     def stop_all(self) -> None:
         for node in self.nodes:

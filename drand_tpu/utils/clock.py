@@ -13,6 +13,8 @@ import heapq
 import itertools
 import time as _time
 
+from ..testing.quiesce import quiesce
+
 
 class Clock:
     """Abstract clock: wall time + async sleeping."""
@@ -78,9 +80,9 @@ class FakeClock(Clock):
         return woke
 
     async def settle(self, rounds: int = 25) -> None:
-        """Let scheduled tasks run until quiescent."""
-        for _ in range(rounds):
-            await asyncio.sleep(0)
+        """Let scheduled tasks, and the worker-thread jobs they started,
+        run until quiescent (``testing/quiesce.py``)."""
+        await quiesce(rounds)
 
     async def advance(self, seconds: float) -> None:
         """Move time forward, waking sleepers in order of their targets."""

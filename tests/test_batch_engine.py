@@ -21,14 +21,11 @@ from drand_tpu.crypto.curves import PointG1
 from drand_tpu.crypto.poly import PriPoly
 
 
-TINY_BUCKETS = (1, 2, 4)  # bound compile count in the suite
-
-
 @pytest.fixture(scope="module")
-def engine():
-    from drand_tpu.ops.engine import BatchedEngine
-
-    return BatchedEngine(buckets=TINY_BUCKETS)
+def engine(engine4):
+    """The suite's shared bucket-4 engine (tests/conftest.py): one
+    compile per graph family."""
+    return engine4
 
 
 @pytest.fixture()
@@ -149,7 +146,7 @@ class TestEngineVsHost:
 
     def test_verify_beacons_v1_corruption(self, engine, threshold_setup):
         *_, sk, pubkey = threshold_setup
-        beacons = _make_chain(sk, 5, v2=False)  # 5 > top bucket: splits
+        beacons = _make_chain(sk, 5, v2=False)  # 5 > the bucket: splits
         beacons[3].signature = beacons[2].signature
         got = engine.verify_beacons(pubkey, beacons)
         assert list(got) == [True, True, True, False, True]

@@ -17,6 +17,7 @@ from drand_tpu.core.config import Config
 from drand_tpu.core.daemon import Drand
 from drand_tpu.key.store import FileStore
 from drand_tpu.net.transport import LocalNetwork
+from drand_tpu.testing.quiesce import wait_for
 from drand_tpu.utils.clock import FakeClock
 
 SECRET = b"setup-secret-0123456789abcdef"
@@ -33,19 +34,17 @@ def make_daemon(i, net, clock, tmp_path, db=False):
     return addr, ks, conf, d
 
 
+def _head(daemon):
+    try:
+        return daemon.beacon.chain.last().round
+    except Exception:  # noqa: BLE001 — no beacon or empty chain yet
+        return 0
+
+
 async def wait_chain(daemon, round_no, timeout=30.0):
-    deadline = asyncio.get_event_loop().time() + timeout
-    while True:
-        try:
-            if daemon.beacon is not None and \
-                    daemon.beacon.chain.last().round >= round_no:
-                return
-        except Exception:
-            pass
-        if asyncio.get_event_loop().time() > deadline:
-            raise TimeoutError(f"{daemon.priv.public.addr} stuck at "
-                               f"{daemon.beacon.chain.last().round}")
-        await asyncio.sleep(0.01)
+    await wait_for(lambda: _head(daemon) >= round_no,
+                   lambda: f"{daemon.priv.public.addr} stuck at "
+                           f"{_head(daemon)}", timeout)
 
 
 async def form_network(n, t, net, clock, tmp_path, db=False):

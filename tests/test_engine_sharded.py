@@ -18,7 +18,10 @@ import types as _types
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.device
+# slow: the mesh graphs (the 16-row pairing on the 8-way mesh beside its
+# single-device twin, the sharded wire-RLC combine) compile for 547 s
+# and over 900 s cold under -n 6
+pytestmark = [pytest.mark.device, pytest.mark.slow]
 import jax
 
 from conftest import sample_count as _sample_count

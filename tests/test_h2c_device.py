@@ -65,11 +65,9 @@ def test_sigs_to_x_rejects_malformed_headers():
 
 
 @pytest.mark.asyncio
-async def test_engine_wire_prep_end_to_end():
+async def test_engine_wire_prep_end_to_end(wire_engine4):
     """verify_beacons with wire_prep=True: valid chain passes; V1 and V2
     corruption each fail exactly the corrupted beacon."""
-    from drand_tpu.ops.engine import BatchedEngine
-
     sk = 0x77AA
     pubkey = PointG1.generator().mul(sk)
     prev = b"\x21" * 32
@@ -80,7 +78,7 @@ async def test_engine_wire_prep_end_to_end():
         beacons.append(Beacon(round=rnd, previous_sig=prev, signature=sig,
                               signature_v2=sig2))
         prev = sig
-    eng = BatchedEngine(buckets=(8,), wire_prep=True)
+    eng = wire_engine4  # 6 checks: two bucket-4 launches
     assert eng.verify_beacons(pubkey, beacons).all()
     import copy
 

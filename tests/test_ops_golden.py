@@ -224,12 +224,14 @@ class TestCurve:
 
 @pytest.fixture(scope="module")
 def verify_compiled():
+    """verify_prepared at the engine's bucket-4 shapes (one public key per
+    row), so this module and the engine suites compile it once."""
     fn = jax.jit(dpair.verify_prepared)
     pub = hc.PointG1.generator().mul(42)
-    sig = hash_to_g2(b"seed").mul(42)
-    pub_d = dpair.g1_affine_to_device(pub)
-    sig_d = dpair.g2_affine_to_device(sig)[None]
-    return fn, pub_d, sig_d
+    pubs = jnp.broadcast_to(dpair.g1_affine_to_device(pub), (4, 2, 32))
+    msg = dpair.g2_affine_to_device(hash_to_g2(b"seed"))
+    msgs = jnp.broadcast_to(msg, (4, 2, 2, 32))
+    return fn, pubs, msgs
 
 
 class TestPairing:
@@ -250,21 +252,17 @@ class TestPairing:
             hp.final_exponentiation(x, canonical=False)
 
     def test_bls_verify_good_and_bad(self, verify_compiled):
-        fn, pub_d, sig_d = verify_compiled
-        msg_d = dpair.g2_affine_to_device(hash_to_g2(b"seed"))[None]
-        assert bool(fn(pub_d, sig_d, msg_d)[0])
-        bad_d = dpair.g2_affine_to_device(hash_to_g2(b"seed").mul(43))[None]
-        assert not bool(fn(pub_d, bad_d, msg_d)[0])
+        fn, pubs, msgs = verify_compiled
+        good_d = dpair.g2_affine_to_device(hash_to_g2(b"seed").mul(42))
+        bad_d = dpair.g2_affine_to_device(hash_to_g2(b"seed").mul(43))
+        assert bool(fn(pubs, jnp.stack([good_d] * 4), msgs)[0])
+        assert not bool(fn(pubs, jnp.stack([bad_d] * 4), msgs)[0])
 
     def test_bls_verify_batch(self, verify_compiled):
-        """Batch axis: one good, one corrupted — elementwise verdicts."""
-        fn, pub_d, _ = verify_compiled
-        good = hash_to_g2(b"seed").mul(42)
-        bad = hash_to_g2(b"seed").mul(99)
-        sigs = jnp.stack([dpair.g2_affine_to_device(good),
-                          dpair.g2_affine_to_device(bad)])  # (2, 2, 2, 32)
-        msg = dpair.g2_affine_to_device(hash_to_g2(b"seed"))
-        msgs = jnp.broadcast_to(msg, (2, 2, 2, 32))
-        out = fn(pub_d, sigs, msgs)
-        assert out.shape == (2,)
-        assert bool(out[0]) and not bool(out[1])
+        """Batch axis: good and corrupted rows — elementwise verdicts."""
+        fn, pubs, msgs = verify_compiled
+        good = dpair.g2_affine_to_device(hash_to_g2(b"seed").mul(42))
+        bad = dpair.g2_affine_to_device(hash_to_g2(b"seed").mul(99))
+        out = fn(pubs, jnp.stack([good, bad, good, bad]), msgs)
+        assert out.shape == (4,)
+        assert np.asarray(out).tolist() == [True, False, True, False]

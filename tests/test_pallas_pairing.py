@@ -12,7 +12,9 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-pytestmark = pytest.mark.device
+# slow: the batch-last Miller loop, final exponentiation and verify
+# graphs compile for 489 s cold under -n 6
+pytestmark = [pytest.mark.device, pytest.mark.slow]
 
 from drand_tpu.crypto import pairing as hp
 from drand_tpu.crypto.curves import PointG1, PointG2

@@ -251,14 +251,11 @@ def test_h2c_memo_counters():
 
 @pytest.mark.device
 class TestDeviceRLC:
-    @pytest.fixture(scope="class")
-    def engine(self):
-        from drand_tpu.ops.engine import BatchedEngine
-
-        eng = BatchedEngine(buckets=(2,))
-        eng.rlc_min = 2
-        eng.rlc_lane_buckets = (4,)
-        return eng
+    @pytest.fixture
+    def engine(self, engine4, monkeypatch):
+        monkeypatch.setattr(engine4, "rlc_min", 2)
+        monkeypatch.setattr(engine4, "rlc_lane_buckets", (4,))
+        return engine4
 
     def test_verify_beacons_rlc_and_fallback(self, engine, keys):
         sk, pub = keys
@@ -272,21 +269,25 @@ class TestDeviceRLC:
         got = engine.verify_beacons(pub, beacons)
         assert list(got) == [True, True, False, True]
 
-    def test_verify_partials_and_agg_rlc(self, engine, threshold_setup):
-        poly, pub = threshold_setup
+    def test_verify_partials_and_agg_rlc(self, engine):
+        # 2-of-4: recovery runs the 8-lane MSM ladder the engine tests
+        # compile too (3-of-4 needs the 16-lane Pippenger graph, a
+        # ten-minute compile of its own)
+        poly = PriPoly.random(2, seed=b"rlc-verify-agg")
+        pub = poly.commit()
         msg = b"rlc-device-round"
         parts = [tbls.sign_partial(s, msg) for s in poly.shares(4)]
         assert engine.verify_partials(pub, msg, parts) == [True] * 4
         assert engine._rlc_ok.get(("g1g2", 4)) is True
-        oks, sig = engine.aggregate_round(pub, msg, parts, 3, 4)
+        oks, sig = engine.aggregate_round(pub, msg, parts, 2, 4)
         assert oks == [True] * 4
-        assert sig == tbls.recover(pub, msg, parts, 3, 4)
+        assert sig == tbls.recover(pub, msg, parts, 2, 4)
         # corrupt one partial: exact per-item verdicts via the fallback
         bad = parts[0][:5] + bytes([parts[0][5] ^ 1]) + parts[0][6:]
         oks, sig = engine.aggregate_round(pub, msg,
-                                          [bad] + parts[1:], 3, 4)
+                                          [bad] + parts[1:], 2, 4)
         assert oks == [False, True, True, True]
-        assert sig == tbls.recover(pub, msg, parts[1:], 3, 4)
+        assert sig == tbls.recover(pub, msg, parts[1:], 2, 4)
 
     def test_escape_hatch_skips_device_rlc(self, engine, keys,
                                            monkeypatch):

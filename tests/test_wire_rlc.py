@@ -15,9 +15,6 @@ The acceptance criteria pinned here:
   2^128-1;
 - DRAND_TPU_BATCH_VERIFY=0 disables the wire_rlc tier like every other
   RLC path.
-
-Kept late-alphabet on purpose: the wire graphs are compile-heavy and the
-tier-1 chunking note in ROADMAP wants such suites at the tail.
 """
 
 import numpy as np
@@ -202,13 +199,10 @@ class TestBatchedBisection:
 
 @pytest.mark.device
 class TestWireRLC:
-    @pytest.fixture(scope="class")
-    def engine(self):
-        from drand_tpu.ops.engine import BatchedEngine
-
-        eng = BatchedEngine(buckets=(4,), wire_prep=True)
-        eng.rlc_min = 2
-        return eng
+    @pytest.fixture
+    def engine(self, wire_engine4, monkeypatch):
+        monkeypatch.setattr(wire_engine4, "rlc_min", 2)
+        return wire_engine4
 
     def test_all_valid_span_two_miller_pairs(self, engine, keys):
         """THE acceptance criterion: an all-valid span through wire_rlc

@@ -640,9 +640,8 @@ def test_real_tree_zero_unsuppressed_high(tmp_path):
 def test_real_tree_no_pairing_class_async_paths():
     """The acceptance criterion, stated directly: NO pairing-class call
     (pairings, Miller loops, MSM, engine dispatch, tbls) is reachable
-    from any async def in drand_tpu without an executor hand-off —
-    except paths carrying a reviewed baseline entry (currently exactly
-    one: the DKG's phase-window deal admission)."""
+    from any async def in drand_tpu without an executor hand-off, and
+    the baseline suppresses none."""
     proj = Project(REPO, packages=("drand_tpu",))
     baseline, problems = load_baseline(
         REPO / "tools" / "analyze" / "baseline.json")
@@ -651,10 +650,10 @@ def test_real_tree_no_pairing_class_async_paths():
              if f.severity == "high" and f.key not in baseline]
     assert highs == [], "\n".join(f.render() for f in highs)
     # the suppression list itself stays tight: reviewed entries only —
-    # one loopblock (DKG deal admission) and one lockheld (engine
-    # singleton init, see test_zz_concurrency)
-    assert len([k for k in baseline if k.startswith("loopblock:")]) <= 1
-    assert len(baseline) <= 2
+    # no loopblock and one lockheld (engine singleton init, see
+    # test_zz_concurrency)
+    assert not [k for k in baseline if k.startswith("loopblock:")]
+    assert len(baseline) <= 1
 
 
 def test_metrics_pass_folds_into_runner():

@@ -4,8 +4,7 @@ contribution bitmaps, reachability/partition-suspect gauges, /healthz
 lag thresholds, ingress-reject counters, DKG phase timelines. No
 scenario peeks at protocol internals.
 
-Late-alphabet filename per the tier-1 chunking convention (ROADMAP
-operational constraint). Everything here is host-only: the structural
+Everything here is host-only: the structural
 crypto mode replaces the pairing-class leaves, so no device graphs and
 no fresh XLA compiles.
 """

@@ -665,11 +665,14 @@ def test_hammer_warm_shapes_single_compile_sample():
     from drand_tpu.crypto import batch
 
     key_op = "verify_beacons"
+    # the shape's own series: an earlier test's dispatch at another
+    # batch bucket is a different sample
+    bucket = metrics.batch_bucket(64)
     with batch._STATE_LOCK:
         batch._WARM_SHAPES.clear()
     before_compile = _hist_count(metrics.ENGINE_COMPILE_SECONDS, op=key_op)
     before_ops = _hist_count(metrics.ENGINE_OP_SECONDS, op=key_op,
-                             path="device")
+                             path="device", batch=bucket)
 
     def one():
         with batch._timed(key_op, "device", 64):
@@ -679,7 +682,7 @@ def test_hammer_warm_shapes_single_compile_sample():
     compiles = _hist_count(metrics.ENGINE_COMPILE_SECONDS,
                            op=key_op) - before_compile
     ops = _hist_count(metrics.ENGINE_OP_SECONDS, op=key_op,
-                      path="device") - before_ops
+                      path="device", batch=bucket) - before_ops
     assert compiles == 1.0
     assert ops == 4 * 200 - 1
     with batch._STATE_LOCK:

@@ -554,15 +554,19 @@ def test_reuseport_worker_smoke():
                     pass
                 await asyncio.sleep(0.3)
             assert streams, "relay workers never came up"
-            # connect until BOTH workers hold at least one stream (the
-            # kernel hashes connections; a couple dozen tries suffice)
-            for _ in range(40):
-                if len({pid for pid, _ in streams}) >= 2:
-                    break
+            # connect until BOTH workers hold a stream: the kernel hashes
+            # connections over the sockets bound so far, and the second
+            # worker may bind a little after the first accepts
+            deadline = time.time() + 30
+            while len(streams) < 2 and time.time() < deadline:
                 resp = await sess.get(url + "/public/latest", headers=SSE)
                 assert resp.status == 200
-                streams.append(
-                    (int(resp.headers["X-Drand-Worker"]), resp))
+                pid = int(resp.headers["X-Drand-Worker"])
+                if pid in {p for p, _ in streams}:
+                    resp.close()
+                    await asyncio.sleep(0.1)
+                else:
+                    streams.append((pid, resp))
             pids = {pid for pid, _ in streams}
             assert len(pids) == 2, f"only saw workers {pids}"
 

@@ -64,8 +64,6 @@ async def test_chaos_detector_matrix(tmp_path):
     below; they are excluded here rather than asserted against
     cross-node noise.)"""
     with structural_crypto(), isolated_observability():
-        metrics.PEER_BREAKER_STATE.clear()  # stray gauge children from
-        # earlier tests would read as pre-existing open breakers
         net = ChaosBeaconNetwork(n=8, t=5, period=PERIOD)
         net.healths[0].note_dkg_complete()  # probe models a real node
         rules = [r for r in default_rules()

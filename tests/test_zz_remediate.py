@@ -434,8 +434,6 @@ async def test_e2e_breaker_open_quorum_pull_closes_breaker():
     after heal the quorum_pull probe answers and the breaker leaves
     OPEN, audited end to end."""
     with structural_crypto(), isolated_observability():
-        metrics.PEER_BREAKER_STATE.clear()  # stray gauge children from
-        # earlier tests would read as pre-existing open breakers
         net = ChaosBeaconNetwork(n=4, t=3, period=PERIOD)
         mgr = _chaos_mgr(net, {"breaker_open"})
         engine = PlaybookEngine(

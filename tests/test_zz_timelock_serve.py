@@ -1,8 +1,7 @@
 """Timelock serving tier (ISSUE 9): crypto, vault, service, HTTP, engine.
 
-Late-alphabet name per the tier-1 chunking convention (ROADMAP): the one
-device test compiles the shared-signature GT graph, which dominates its
-chunk — run via tools/tier1_chunks.sh.
+The one device test compiles the shared-signature GT graph; the tier-1
+scheduler (tests/conftest.py, GRAPH_GROUPS) sends it out early.
 
 Covers the adversarial matrix (wrong-round signature, truncated V,
 flipped W byte, pre-V2 beacon, cross-chain ciphertext, empty/large
@@ -415,7 +414,7 @@ async def test_http_routes_submit_status_etag(tmp_path, host_mode):
 # ---------------------------------------------------------------- engine
 
 @pytest.mark.parametrize("mode", ["auto", "device"])
-def test_kat_failure_is_never_silent(monkeypatch, mode):
+def test_kat_failure_is_never_silent(monkeypatch, mode, engine4):
     """A device engine whose timelock KAT fails must never decide the
     round. Auto mode falls back to the host shared-signature tier and
     records it in the fallback ledger and engine_device_fallbacks;
@@ -423,7 +422,7 @@ def test_kat_failure_is_never_silent(monkeypatch, mode):
     from drand_tpu import metrics
     from drand_tpu.ops import engine as eng_mod
 
-    eng = eng_mod.BatchedEngine(buckets=(4,))
+    eng = engine4
 
     def kat_fails(b):
         return eng_mod._kat(False, f"timelock bucket {b}")
@@ -453,6 +452,7 @@ def test_kat_failure_is_never_silent(monkeypatch, mode):
         batch.reset_fallback_ledger()
 
 
+@pytest.mark.device
 def test_device_round_open_one_dispatch_meter_and_oracle_identical():
     """The acceptance proof: K pending ciphertexts (including the
     adversarial rows) open via ONE batched engine dispatch — 1 product

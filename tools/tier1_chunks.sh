@@ -1,15 +1,14 @@
 #!/usr/bin/env bash
 # Run the tier-1 suite in N sequential pytest chunks.
 #
-# The single-invocation tier-1 command (ROADMAP.md) cannot finish inside
-# its 870 s cap on the 1-core box — XLA:CPU compiles dominate and the
-# seed already timed out (CHANGES.md PR 1 note). Splitting the test
-# FILES round-robin into N chunks keeps every invocation under the cap
-# while preserving the exact same selection (-m 'not slow'); the
-# persistent .jax_cache is shared across chunks, so compile work is
-# paid once. Round-robin (not contiguous) so the alphabetical cluster
-# of compile-heavy device suites (test_bl_*, test_pallas_*, ...)
-# spreads across chunks.
+# For a host without the cores for the xdist tier-1 run (`-n 6`): one
+# invocation on one core cannot fit the cap, as XLA:CPU compiles
+# dominate. Splitting the test FILES round-robin into N chunks keeps
+# every invocation under the cap while preserving the exact same
+# selection (-m 'not slow'); the persistent .jax_cache is shared across
+# chunks, so compile work is paid once. Round-robin (not contiguous) so
+# the alphabetical cluster of compile-heavy device suites (test_bl_*,
+# test_pallas_*, ...) spreads across chunks.
 #
 # Usage:
 #   tools/tier1_chunks.sh [N] [--list] [extra pytest args...]
@@ -30,111 +29,10 @@
 # on round-robin luck as the file list grows.
 #
 # Registration is by glob: every tests/test_*.py is picked up
-# automatically. New suites MUST keep the conventions the chunking
-# relies on: compile-heavy device suites and new subsystem suites go
-# late-alphabet (test_zz_*) so the capped single tier-1 invocation
-# keeps its early-dot throughput. Currently registered late-alphabet:
-#   test_zz_analyze.py     static-analysis suite (host-only, <60 s,
-#                          no backend init — pure AST + one aiohttp
-#                          harness)
-#   test_zz_chaos.py       chaos network simulator (host-only,
-#                          structural crypto — no pairings, no
-#                          compiles; ~10 s)
-#   test_zz_concurrency.py concurrency-analysis tier: lockheld/
-#                          threadshare/awaitatomic fixtures, thread
-#                          hammers, cache-race regressions (host-only,
-#                          pure AST + threads, no compiles; ~7 s).
-#                          CONFLICTS check vs test_zz_analyze: both
-#                          parse the full tree (~2 s each, CPU-bound,
-#                          no shared mutable state, no clocks) — they
-#                          coexist in one chunk fine; no pair entry
-#                          needed.
-#   test_zz_dkg_scale.py   large-group ceremony tier: batched-phase
-#                          verdict bit-identity vs per-item oracles
-#                          (lockstep G1 membership, parse_commits,
-#                          comb share checks, RLC reshare bindings),
-#                          structural n=48/64 ceremony + reshare,
-#                          FakeClock chunked-admission regression,
-#                          attributable-reject counters (host-pinned
-#                          by an autouse fixture; real crypto only at
-#                          small n; ~60 s). CONFLICTS evaluation vs
-#                          test_daemon/test_mock_and_scale: runs DKG
-#                          phasers but only on its OWN LocalBoards
-#                          with a private FakeClock (fast-sync
-#                          elsewhere), resets the FLIGHT dkg ring
-#                          around each use and asserts counter
-#                          DELTAS — no shared timers or state; no
-#                          pair entry needed.
-#   test_zz_fanout.py      edge fan-out push tier: SSE/NDJSON hub,
-#                          shedding, segment store, SO_REUSEPORT
-#                          worker smoke (host-only, no pairings except
-#                          the worker smoke's ~15 real signatures;
-#                          ~15 s wall). CONFLICTS check vs
-#                          test_daemon/test_mock_and_scale: the worker
-#                          smoke spawns 3 short-lived relay processes
-#                          on the wall clock but runs no DKG and no
-#                          reshare timers — no contention pair needed.
-#   test_zz_flight.py      threshold flight recorder suite (host-only)
-#   test_zz_incident.py    incident engine: chaos-driven detector
-#                          matrix, ts-ring/bundle rotation, restart
-#                          persistence, bundle hygiene, ?n= matrix
-#                          (host-only; structural crypto + one real
-#                          share synthesis, no pairings, no compiles;
-#                          ~5 s). CONFLICTS evaluation vs
-#                          test_zz_chaos/test_zz_analyze: same
-#                          structural-crypto FakeClock harness (~7 s
-#                          CPU, no wall-clock timers, no DKG/reshare
-#                          phasers) and its own recorder instances —
-#                          coexists in one chunk fine; no pair entry
-#                          needed.
-#   test_zz_obs_health.py  chain-health SLO / OTLP export suite
-#   test_zz_remediate.py   auto-remediation plane: playbook-engine
-#                          guardrails, bounded supervisor, ledger-sink
-#                          analyzer fixtures, chaos-oracle e2e matrix,
-#                          /debug/remediation ?n= (host-only,
-#                          structural crypto + FakeClock; ~6 s).
-#                          CONFLICTS evaluation vs test_zz_chaos/
-#                          test_zz_incident: same structural-crypto
-#                          harness, per-test IncidentManager/
-#                          PlaybookEngine instances, the one singleton
-#                          test detaches in its finally — coexists in
-#                          one chunk fine; no pair entry needed.
-#   test_zz_client_catchup.py  million-client catch-up tier: adaptive
-#                          RLC span walk, pipelined fetch/verify
-#                          cancel-resume, trust ring, checkpoint
-#                          bootstrap/forgery matrix, /checkpoints/
-#                          latest route (host-only; structural crypto
-#                          plus ~45 real signatures on 40-round
-#                          chains, batch dispatch pinned to host by
-#                          an autouse fixture; ~6 s). CONFLICTS
-#                          evaluation vs test_zz_chaos/
-#                          test_zz_incident: same structural-crypto
-#                          patch pattern with per-test client/network
-#                          instances, no wall-clock timers, no DKG/
-#                          reshare phasers — coexists in one chunk
-#                          fine; no pair entry needed.
-#   test_zz_selfheal.py    self-healing plane: retry policy, breakers,
-#                          quorum repair, stale serving (host-only,
-#                          structural crypto; ~5 s)
-#   test_zz_timelock_serve.py  timelock serving tier
-#   test_zz_vault_scale.py segment timelock vault (ISSUE 20): shard
-#                          math coverage, SQLite<->segment CLI
-#                          migration equivalence both directions,
-#                          O(1)-at-depth status/pending_count,
-#                          chunked-open crash resume, two-worker
-#                          partitioned sweep, SSE open-notify +
-#                          shedding, restart persistence (host-pinned
-#                          by an autouse fixture; real crypto only on
-#                          handfuls of ciphertexts; ~30 s). CONFLICTS
-#                          evaluation vs test_daemon/
-#                          test_mock_and_scale: pure tmp_path vaults
-#                          and in-process aiohttp TestClient, no DKG/
-#                          reshare phasers, no wall-clock timers
-#                          beyond short sweep polls; vs
-#                          test_zz_timelock_serve: same host-pinned
-#                          batch fixture pattern, per-test vault
-#                          dirs — coexists in one chunk fine; no pair
-#                          entry needed.
+# automatically. File order only spreads files over chunks: the
+# single-invocation run (`-n 6 --dist load`) orders its work with the
+# scheduler in tests/conftest.py (GRAPH_GROUPS: device-graph groups
+# first, each on one worker), so no suite needs a late-alphabet name.
 #
 # Exit status: 0 iff every chunk passed.
 
