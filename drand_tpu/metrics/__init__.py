@@ -37,6 +37,10 @@ Additions beyond the reference (the TPU engine + round tracing):
       device wire-pipeline RLC tier (ops/engine.py verify_wire_rlc —
       device hash-to-curve + in-graph lane-MSM, 2 Miller pairs per
       catch-up span with no host hashing)
+  engine_wire_rlc_slices_total{prep}   [private] wire-RLC combine slices
+      launched while the span's previous slice was still running on the
+      device (overlapped) or with the device idle (exposed: a span's
+      first, or a device that finished before the host)
   hash_to_g2_cache_requests{result}    [private] hash-to-G2 memo
       hit/miss counters (crypto/hash_to_curve.py per-round keyed LRU)
 Timelock serving tier (drand_tpu/timelock, ISSUE 9):
@@ -299,6 +303,14 @@ ENGINE_BATCHES = Counter(
 ENGINE_FALLBACKS = Counter(
     "engine_device_fallbacks", "Device-engine failures that fell back to host",
     registry=REGISTRY)
+ENGINE_WIRE_RLC_SLICES = Counter(
+    "engine_wire_rlc_slices_total",
+    "Wire-RLC combine slices by host preparation (ops/engine.py "
+    "verify_wire_rlc): overlapped = launched while the span's previous "
+    "slice was still running on the device; exposed = launched with "
+    "the device idle (a span's first slice, or a device that finished "
+    "the previous slice before the host finished this one)",
+    ["prep"], registry=REGISTRY)
 H2C_CACHE_REQUESTS = Counter(
     "hash_to_g2_cache_requests",
     "hash_to_g2 memo lookups by result (hit|miss) — the per-round "

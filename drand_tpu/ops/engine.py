@@ -116,6 +116,13 @@ def _drain(launches) -> np.ndarray:
     return np.asarray(jnp.stack(devs))
 
 
+def _running(launched) -> bool:
+    """True while the device is still computing any output of a
+    ``BatchedEngine._launch_wire_chunk`` result (asks, never waits)."""
+    _, outs = launched
+    return not all(o.is_ready() for o in outs)
+
+
 def shard_map_unchecked(f, **kw):
     """``jax.shard_map`` with the varying-axes checker off (a post-gather
     fold makes every device compute the identical total, which the
@@ -1030,10 +1037,12 @@ class BatchedEngine:
     # hash-to-curve + decompression feed an in-graph lane-MSM, so a
     # catch-up span costs 2 Miller loops end-to-end with no host hashing
     # either (the host does only SHA-256 expansion, byte unpacking and
-    # scalar sampling). Same discipline as every other graph family:
-    # per-bucket KAT gate against the host MSM, and a wrong verdict can
-    # only be a false REJECT (the caller falls back to the per-item wire
-    # graph for exact verdicts).
+    # scalar sampling). A span above the bucket runs as slices, and the
+    # host prepares each slice while the device runs the one before it
+    # (the launch / finish halves of the combine). Same discipline as
+    # every other graph family: per-bucket KAT gate against the host
+    # MSM, and a wrong verdict can only be a false REJECT (the caller
+    # falls back to the per-item wire graph for exact verdicts).
 
     def wire_rlc_active(self, n_checks: int) -> bool:
         """True iff a span of ``n_checks`` wire checks takes the device
@@ -1070,6 +1079,17 @@ class BatchedEngine:
         KAT probes pin the path they gate); None consults the sharded
         KAT cache — never probes — so dispatch follows whatever verdict
         bucket selection already established."""
+        return self._finish_wire_chunk(
+            self._launch_wire_chunk(checks, cs, b, dst, sharded))
+
+    def _launch_wire_chunk(self, checks, cs, b: int, dst: bytes,
+                           sharded: bool | None = None):
+        """The first half of _combine_wire_chunk: host preparation, then
+        dispatch of the combine; returns (count, device outputs) without
+        synchronizing. Every array handed to the device is allocated
+        for this slice and never written again, so a slice in flight
+        cannot see the next one's preparation (jnp.asarray may alias
+        numpy memory on the CPU backend)."""
         from . import h2c
 
         n = len(checks)
@@ -1099,6 +1119,13 @@ class BatchedEngine:
             out = self._wire_rlc_jit(
                 jnp.asarray(xs), jnp.asarray(sign), jnp.asarray(u),
                 jnp.asarray(live), jnp.asarray(bits))
+        return n, out
+
+    @staticmethod
+    def _finish_wire_chunk(launched):
+        """The second half of _combine_wire_chunk: read back one
+        launched combine and convert its sums to host points."""
+        n, out = launched
         ok, sx, sy, sinf, mx, my, minf = (np.asarray(o) for o in out)
         ok = ok.astype(bool)[:n]
         if not ok.any():
@@ -1167,8 +1194,14 @@ class BatchedEngine:
         to the per-item wire graph — on a degenerate combination or a
         failed combined check (some signature is bad; the fallback
         produces the exact verdicts). Spans above the
-        bucket chunk through it with one scalar vector, chunk sums added
-        on host, ONE pairing row at the end."""
+        bucket chunk through it with one scalar vector and ONE pairing
+        row at the end; their slices are pipelined two deep, the host
+        preparing slice i+1 while slice i runs on the device, and each
+        slice's sums are added on the host as it is read back.
+        engine_wire_rlc_slices_total counts a slice "overlapped" when
+        the slice before it was still running once it was launched,
+        else "exposed" (a span's first slice, or a device that finished
+        before the host did)."""
         n = len(checks)
         if n == 0:
             return np.zeros(0, dtype=bool)
@@ -1176,19 +1209,39 @@ class BatchedEngine:
             return None
         b = self._checked_bucket(n, check=self._wire_rlc_check,
                                  buckets=self._wire_rlc_buckets())
+        from .. import metrics
+
         cs = batch_verify.rlc_scalars(n)
         ok_mask = np.zeros(n, dtype=bool)
         s_acc = m_acc = None
-        for lo in range(0, n, b):
-            hi = min(lo + b, n)
-            got = self._combine_wire_chunk(checks[lo:hi], cs[lo:hi], b, dst)
-            if got is None:
-                return None
-            ok_chunk, s_chunk, m_chunk = got
-            ok_mask[lo:hi] = ok_chunk
-            if s_chunk is not None:
-                s_acc = s_chunk if s_acc is None else s_acc + s_chunk
-                m_acc = m_chunk if m_acc is None else m_acc + m_chunk
+        # two-deep pipeline: slice i+1 is prepared and launched before
+        # slice i is read back (the trailing None only drains). A
+        # slice's preparation was hidden only if the slice before it is
+        # still on the device once this one is launched.
+        in_flight = None
+        for lo in [*range(0, n, b), None]:
+            launched = None
+            if lo is not None:
+                hi = min(lo + b, n)
+                launched = lo, self._launch_wire_chunk(
+                    checks[lo:hi], cs[lo:hi], b, dst)
+                if in_flight is not None and _running(in_flight[1]):
+                    metrics.ENGINE_WIRE_RLC_SLICES.labels(
+                        prep="overlapped").inc()
+                else:
+                    metrics.ENGINE_WIRE_RLC_SLICES.labels(
+                        prep="exposed").inc()
+            if in_flight is not None:
+                done, pending = in_flight
+                got = self._finish_wire_chunk(pending)
+                if got is None:
+                    return None  # the slice in flight is dropped unread
+                ok_chunk, s_chunk, m_chunk = got
+                ok_mask[done:done + len(ok_chunk)] = ok_chunk
+                if s_chunk is not None:
+                    s_acc = s_chunk if s_acc is None else s_acc + s_chunk
+                    m_acc = m_chunk if m_acc is None else m_acc + m_chunk
+            in_flight = launched
         if s_acc is None:
             return ok_mask  # nothing decodable: every check already False
         if s_acc.is_infinity() or m_acc.is_infinity():

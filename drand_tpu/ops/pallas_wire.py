@@ -264,9 +264,10 @@ def wire_rlc_pl(u_pairs_np, sig_x_np, sign_np, live_np, bits_np):
     """Host entry for the wire-RLC combine: u_pairs_np (B, 2, 2, 32)
     batch-leading (ops/h2c.msgs_to_u layout); sig_x_np (B, 2, 32);
     sign_np/live_np (B,) bool; bits_np (B, 128) MSB-first int32 scalar
-    bits. Returns numpy (ok (B,), s_x (2, 32), s_y, s_inf (), m_x, m_y,
-    m_inf) — the same shapes as the XLA combine graph so the engine
-    consumes either interchangeably."""
+    bits. Returns the device arrays (ok (B,), s_x (2, 32), s_y, s_inf (),
+    m_x, m_y, m_inf) un-synced — the same shapes as the XLA combine
+    graph, so the engine consumes either interchangeably and prepares
+    the next slice while this one runs."""
     b = u_pairs_np.shape[0]
     u_bl = jnp.asarray(np.moveaxis(u_pairs_np, 0, -1))        # (2, 2, 32, B)
     sig_bl = jnp.asarray(np.moveaxis(sig_x_np, 0, -1))        # (2, 32, B)
@@ -276,7 +277,7 @@ def wire_rlc_pl(u_pairs_np, sig_x_np, sign_np, live_np, bits_np):
         np.broadcast_to(live_np.astype(np.int32)[None, :], (8, b)))
     bits_bl = jnp.asarray(bits_np.T.astype(np.int32))         # (128, B)
     out = _wire_rlc_pl(u_bl, sig_bl, sign_mask, live_mask, bits_bl, b)
-    return tuple(np.asarray(o) for o in out)
+    return out
 
 
 def verify_wire_pl(pubkey_aff, u_pairs_np, sig_x_np, sign_np,

@@ -26,6 +26,7 @@ from drand_tpu.chain.beacon import Beacon, message
 from drand_tpu.crypto import batch, batch_verify, bls
 from drand_tpu.crypto import pairing as hpairing
 from drand_tpu.crypto.curves import PointG1, PointG2
+from drand_tpu.ops import engine as engine_mod
 
 
 @pytest.fixture(scope="module")
@@ -41,6 +42,41 @@ def _make_chain(sk: int, nrounds: int) -> list[Beacon]:
         out.append(Beacon(round=rnd, previous_sig=prev, signature=sig))
         prev = sig
     return out
+
+
+def _pinned_scalars(n: int) -> list[int]:
+    """Fixed nonzero 128-bit scalars, so two runs of a span combine
+    alike."""
+    return [(0x9E3779B97F4A7C15F39CC0605CEDC835 * (i + 1)) % (1 << 128) | 1
+            for i in range(n)]
+
+
+def _serial_wire_rlc(engine, checks, cs, dst, b: int = 4):
+    """A span as the serial composition of _combine_wire_chunk: the
+    decode-ok mask and the summed (Σc·sig, Σc·H(m))."""
+    mask, s_acc, m_acc = [], None, None
+    for lo in range(0, len(checks), b):
+        ok, s, m = engine._combine_wire_chunk(checks[lo:lo + b],
+                                              cs[lo:lo + b], b, dst)
+        mask += list(ok)
+        if s is not None:
+            s_acc = s if s_acc is None else s_acc + s
+            m_acc = m if m_acc is None else m_acc + m
+    return mask, s_acc, m_acc
+
+
+def _slice_delta(engine, pub, sk, nrounds):
+    """(overlapped, exposed) slices one all-valid span of ``nrounds``
+    adds to engine_wire_rlc_slices_total."""
+    def counts():
+        return tuple(_sample_count(
+            metrics.REGISTRY, "engine_wire_rlc_slices", prep=p)
+            for p in ("overlapped", "exposed"))
+
+    before = counts()
+    got = engine.verify_beacons_wire_rlc(pub, _make_chain(sk, nrounds))
+    assert got is not None and got.all()
+    return tuple(a - b for a, b in zip(counts(), before))
 
 
 def _oracle(pub, beacons):
@@ -247,6 +283,115 @@ class TestWireRLC:
         assert engine.verify_beacons_wire_rlc(pub, beacons) is None
         got = engine.verify_beacons(pub, beacons)
         assert list(got) == [True, True, False, True]
+
+    def test_bad_signature_mid_span_pipelined(self, engine, keys,
+                                              monkeypatch):
+        """A span of three slices runs them pipelined, and its verdicts
+        are those of the serial composition of _combine_wire_chunk on
+        the same scalars: a malformed signature in the last slice is a
+        per-item False with the same combined sums, and a bad signature
+        in the middle slice still gives None, then the exact verdicts
+        of the fallback."""
+        from drand_tpu.chain import beacon as chain_beacon
+        from drand_tpu.crypto.hash_to_curve import DEFAULT_DST_G2
+
+        sk, pub = keys
+        monkeypatch.setattr(batch_verify, "rlc_scalars", _pinned_scalars)
+        rows = []
+        real_verify_bls = engine.verify_bls
+
+        def spy(batch_rows):
+            rows.append(batch_rows[0])
+            return real_verify_bls(batch_rows)
+
+        monkeypatch.setattr(engine, "verify_bls", spy)
+        beacons = _make_chain(sk, 10)  # 10 checks over bucket 4: 3 slices
+        beacons[9].signature = b"\x00" * 96
+        checks = [(chain_beacon.message(b.round, b.previous_sig),
+                   b.signature) for b in beacons]
+        cs = _pinned_scalars(len(checks))
+
+        got = engine.verify_beacons_wire_rlc(pub, beacons)
+        mask, s_acc, m_acc = _serial_wire_rlc(engine, checks, cs,
+                                              DEFAULT_DST_G2)
+        assert got is not None
+        assert list(got) == mask == [True] * 9 + [False]
+        assert rows == [(pub, s_acc, m_acc)]
+
+        beacons[5].signature = beacons[4].signature
+        checks[5] = (checks[5][0], beacons[5].signature)
+        assert engine.verify_beacons_wire_rlc(pub, beacons) is None
+        mask, s_acc, m_acc = _serial_wire_rlc(engine, checks, cs,
+                                              DEFAULT_DST_G2)
+        assert not real_verify_bls([(pub, s_acc, m_acc)])[0]
+        got = engine.verify_beacons(pub, beacons)
+        assert list(got) == _oracle(pub, beacons)
+        assert list(got) == [True] * 5 + [False] + [True] * 3 + [False]
+
+    def test_degenerate_mid_span_drops_later_slices(self, engine, keys,
+                                                    monkeypatch):
+        """A combination that degenerates to infinity in the middle
+        slice (zero scalars there) returns None; the last slice, already
+        launched behind it, is never read back."""
+        sk, pub = keys
+
+        def scalars(n):
+            cs = _pinned_scalars(n)
+            cs[4:8] = [0] * 4
+            return cs
+
+        monkeypatch.setattr(batch_verify, "rlc_scalars", scalars)
+        assert engine._wire_rlc_check(4)  # the KAT, before the spies
+        launched, finished = [], []
+        real_launch = engine._launch_wire_chunk
+        real_finish = engine._finish_wire_chunk
+
+        def launch(checks, *a, **k):
+            launched.append(len(checks))
+            return real_launch(checks, *a, **k)
+
+        def finish(pending):
+            finished.append(pending)
+            return real_finish(pending)
+
+        monkeypatch.setattr(engine, "_launch_wire_chunk", launch)
+        monkeypatch.setattr(engine, "_finish_wire_chunk", finish)
+        assert engine.verify_beacons_wire_rlc(pub, _make_chain(sk, 10)) \
+            is None
+        assert launched == [4, 4, 2]
+        assert len(finished) == 2
+
+    def test_slice_counter_overlapped_and_exposed(self, engine, keys,
+                                                  monkeypatch):
+        """Every slice is counted once and a span's first is exposed.
+        While the device is still on the slice before (as on a chip,
+        where a slice outlasts its successor's preparation), a span of
+        k slices counts k-1 overlapped and 1 exposed; a span of one
+        slice counts 1 exposed."""
+        sk, pub = keys
+        overlapped, exposed = _slice_delta(engine, pub, sk, 10)
+        assert overlapped + exposed == 3 and exposed >= 1
+        monkeypatch.setattr(engine_mod, "_running", lambda launched: True)
+        assert _slice_delta(engine, pub, sk, 10) == (2, 1)
+        assert _slice_delta(engine, pub, sk, 4) == (0, 1)
+
+    def test_slice_counter_sees_a_synced_launch(self, engine, keys,
+                                                monkeypatch):
+        """The counter reads the device, not the slice's place in the
+        loop: a launch that waits for its own outputs leaves nothing in
+        flight, so every slice of the span counts exposed."""
+        import jax
+
+        sk, pub = keys
+        real_launch = engine._launch_wire_chunk
+
+        def synced(*a, **k):
+            n, out = real_launch(*a, **k)
+            jax.block_until_ready(out)
+            return n, out
+
+        monkeypatch.setattr(engine, "_launch_wire_chunk", synced)
+        assert _slice_delta(engine, pub, sk, 10) == (0, 3)
 
     def test_combine_failure_propagates(self, engine, keys, monkeypatch):
         """A combine graph that fails to run is a fault, not a disabled

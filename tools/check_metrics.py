@@ -62,6 +62,8 @@ KNOWN_ENGINE_PATHS = {"host", "device", "host_rlc", "wire_rlc",
 # known label VALUES per labelled counter whose cardinality is a fixed
 # enum (new values need a deliberate catalogue update here + README)
 KNOWN_LABEL_VALUES = {
+    # ops/engine.py verify_wire_rlc: one literal per branch, per slice
+    "engine_wire_rlc_slices_total": {"prep": {"overlapped", "exposed"}},
     "hash_to_g2_cache_requests": {"result": {"hit", "miss"}},
     "timelock_gt_cache_requests": {"result": {"hit", "miss"}},
     "timelock_ciphertexts_total": {"result": {"submitted", "opened",
